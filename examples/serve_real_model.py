@@ -1,5 +1,9 @@
-"""End-to-end driver: serve a REAL (reduced) JAX model with batched
-multimodal requests through the TCM engine on CPU.
+"""End-to-end driver: serve the REDUCED qwen2-vl JAX model with batched
+multimodal requests through the TCM engine — sized for the CPU, where the
+paged attention runs on the pure-JAX gather path. The published widths
+serve on a TPU with compiled Pallas kernels through
+``python -m repro.launch.serve --executor real --full-width`` (or
+``python chip_smoke.py``, which also checks the kernels on the device).
 
 Every token is actually computed — the batched paged-KV execution path
 runs each engine iteration as one packed prefill call plus one fused

@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the kernel body
-executes in Python); on TPU set ``interpret=False``. ``ref.py`` holds the
-pure-jnp oracles used by tests and by the engine's portable fallback path.
+The kernels compile natively on a TPU backend and run in interpret mode
+(the kernel body executes in Python) on any other; the choice is made per
+call, when the caller traces, from the backend that call runs on.
+``ref.py`` holds the pure-jnp oracles used by tests and by the engine's
+portable fallback path.
 """
 from __future__ import annotations
 
@@ -15,8 +17,10 @@ from .prefill_attention import prefill_attention as _prefill
 from .ref import (ref_paged_attention, ref_paged_prefill_attention,
                   ref_prefill_attention)
 
-# flipped to False on real TPU deployments
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode() -> bool:
+    """True where the kernels would run interpreted: any non-TPU backend."""
+    return jax.default_backend() != "tpu"
 
 
 def prefill_attention(q, k, v, *, q_start=0, window=0, softcap=0.0,
@@ -25,7 +29,7 @@ def prefill_attention(q, k, v, *, q_start=0, window=0, softcap=0.0,
         return ref_prefill_attention(q, k, v, q_start=q_start, window=window,
                                      softcap=softcap)
     return _prefill(q, k, v, q_start=q_start, window=window, softcap=softcap,
-                    interpret=INTERPRET)
+                    interpret=interpret_mode())
 
 
 def paged_attention(q, k_pages, v_pages, block_table, lengths, *, softcap=0.0,
@@ -34,7 +38,7 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *, softcap=0.0,
         return ref_paged_attention(q, k_pages, v_pages, block_table, lengths,
                                    softcap=softcap)
     return _paged(q, k_pages, v_pages, block_table, lengths, softcap=softcap,
-                  interpret=INTERPRET)
+                  interpret=interpret_mode())
 
 
 def paged_prefill_attention(q, k_pages, v_pages, block_table, q_start,
@@ -43,4 +47,4 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_start,
         return ref_paged_prefill_attention(q, k_pages, v_pages, block_table,
                                            q_start, new_lens, softcap=softcap)
     return _paged_prefill(q, k_pages, v_pages, block_table, q_start, new_lens,
-                          softcap=softcap, interpret=INTERPRET)
+                          softcap=softcap, interpret=interpret_mode())

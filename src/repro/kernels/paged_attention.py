@@ -39,39 +39,35 @@ def _kernel(block_table, lengths, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p < n_pages)
     def _step():
-        q = q_ref[0].astype(jnp.float32)          # (KV, G, hd)
-        k = k_ref[0].astype(jnp.float32)          # (page_size, KV, hd)
-        v = v_ref[0].astype(jnp.float32)
-        KV, G, hd = q.shape
-
-        s = jnp.einsum("kgd,tkd->kgt", q, k) * sm_scale      # (KV, G, T)
-        if softcap > 0:
-            s = jnp.tanh(s / softcap) * softcap
         tpos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        s = jnp.where(tpos < length, s, NEG_INF)
+            jnp.int32, (1, page_size), 1)
+        # one 2-D matmul pair per KV head: the page block holds every head,
+        # and a static head index is a plain strided VMEM load
+        for h in range(q_ref.shape[0]):
+            q = q_ref[h].astype(jnp.float32)            # (G, hd)
+            k = k_ref[:, h, :].astype(jnp.float32)      # (page_size, hd)
+            v = v_ref[:, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, _NT) * sm_scale      # (G, T)
+            if softcap > 0:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(tpos < length, s, NEG_INF)
 
-        m_prev = m_ref[...]                        # (KV, G, 1)
-        m_cur = jnp.max(s, axis=2, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        pexp = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(pexp, axis=2, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.einsum("kgt,tkd->kgd", pexp, v)
-        m_ref[...] = m_new
+            m_prev = m_ref[h]                            # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            pexp = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(pexp, axis=1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(pexp, v)
+            m_ref[h] = m_new
 
     @pl.when(p == max_pages - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
             o_ref.dtype)
 
 
-LANE = 128     # TPU lane width: last dim of every tile
-SUBLANE = 8    # f32 sublane width: second-to-last dim
-
-
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
+# contract the last dims of both operands: (M, d) x (N, d) -> (M, N)
+_NT = (((1,), (1,)), ((), ()))
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -80,32 +76,19 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
     """q: (B,H,hd); k_pages/v_pages: (P,page_size,KV,hd);
     block_table: (B,max_pages) int32; lengths: (B,) int32. -> (B,H,hd).
 
-    Small ``head_dim``/``KV`` are zero-padded up to the TPU tile minima
-    (lane 128 / sublane 8) — required by Mosaic on the compiled path and
-    applied on the interpret path too so it exercises the same block
-    geometry. Zero-padding is exact (padded kv-heads carry zero q/k/v and
-    are sliced off, zero head-dim columns contribute nothing to the dot
-    products). ``sm_scale`` always uses the *original* head_dim.
+    The page pool is read in place: each K/V block is one whole page,
+    ``(page_size, KV, hd)``, whose last two dims span the array's, so no
+    head or lane padding is needed and nothing copies the pool.
     """
     B, H, hd = q.shape
     P, page_size, KV, _ = k_pages.shape
     max_pages = block_table.shape[1]
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
-    orig_kv, orig_hd = KV, hd
-    if hd % LANE or KV % SUBLANE:
-        hd_p = _round_up(hd, LANE)
-        kv_p = _round_up(KV, SUBLANE)
-        qg = jnp.pad(qg, ((0, 0), (0, kv_p - KV), (0, 0), (0, hd_p - hd)))
-        k_pages = jnp.pad(
-            k_pages, ((0, 0), (0, 0), (0, kv_p - KV), (0, hd_p - hd)))
-        v_pages = jnp.pad(
-            v_pages, ((0, 0), (0, 0), (0, kv_p - KV), (0, hd_p - hd)))
-        KV, hd = kv_p, hd_p
 
     kernel = functools.partial(
         _kernel, page_size=page_size, max_pages=max_pages, softcap=softcap,
-        sm_scale=1.0 / math.sqrt(orig_hd))
+        sm_scale=1.0 / math.sqrt(hd))
 
     def _kv_map(b, p, bt, ln):
         # grid steps past the row's live pages are predicated off by
@@ -116,15 +99,17 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
         last = jnp.maximum((ln[b] + page_size - 1) // page_size - 1, 0)
         return (bt[b, jnp.minimum(p, last)], 0, 0, 0)
 
+    sq = pl.squeezed
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda b, p, bt, ln: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page_size, KV, hd), _kv_map),
-            pl.BlockSpec((1, page_size, KV, hd), _kv_map),
+            pl.BlockSpec((sq, KV, G, hd), lambda b, p, bt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((sq, page_size, KV, hd), _kv_map),
+            pl.BlockSpec((sq, page_size, KV, hd), _kv_map),
         ],
-        out_specs=pl.BlockSpec((1, KV, G, hd), lambda b, p, bt, ln: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((sq, KV, G, hd),
+                               lambda b, p, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KV, G, 1), jnp.float32),
             pltpu.VMEM((KV, G, 1), jnp.float32),
@@ -137,8 +122,7 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
     )(block_table, lengths, qg, k_pages, v_pages)
-    out = out[:, :orig_kv, :, :orig_hd]
     # length-0 guard (padding rows in bucketed batches): the accumulator
     # never ran, so force exact zeros rather than 0/eps division noise.
     out = jnp.where(lengths[:, None, None, None] > 0, out, 0.0)
-    return out.reshape(B, H, orig_hd)
+    return out.reshape(B, H, hd)

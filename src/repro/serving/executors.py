@@ -6,7 +6,9 @@ device throughput, quadratic attention term, per-iteration decode cost,
 modality preprocess/encode stage costs. Used for workload-scale scheduler
 experiments (the scheduler sees the identical engine API either way).
 
-ModelExecutor — runs the real JAX model (reduced config). The default
+ModelExecutor — runs the real JAX model: the reduced config on the CPU
+(tests, examples; Pallas kernels interpreted) or the published widths on
+a TPU (compiled kernels; launch/serve.py --full-width). The default
 batched mode executes each engine iteration as one jit-compiled packed
 prefill step plus one fused decode step over the whole running set, with
 per-layer KV in paged stores indexed by the engine allocator's block
@@ -27,6 +29,14 @@ import numpy as np
 from repro.core.profiler import ProfileRecord
 
 from .request import TERMINAL_STATES, Modality, Request
+
+
+def device_bytes_limit() -> int | None:
+    """Bytes the default device may allocate, where its backend reports
+    it (TPU does; the CPU backend reports nothing)."""
+    import jax
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
 
 
 class SlotCapacityError(RuntimeError):
@@ -277,7 +287,8 @@ class ExecutorConfig:
 
 
 class ModelExecutor:
-    """Real-JAX backend over a reduced model.
+    """Real-JAX backend: a reduced model on the CPU, or a configuration's
+    published widths on a TPU.
 
     Batched mode (default, attention-only archs): per-layer KV lives in
     ``PagedStackStore`` page arrays shared by every request; the engine
@@ -295,8 +306,8 @@ class ModelExecutor:
     context cap while jit recompiles stay O(log) per axis (counted in
     ``recompile_keys``, bounded by ``recompile_bound()``).
 
-    ``legacy=True`` (or an arch the paged protocol does not cover —
-    SSM/xLSTM/sliding-window/cross-attn) runs the seed's per-request
+    ``legacy=True`` (or, off the TPU, an arch the paged protocol does not
+    cover — SSM/xLSTM/sliding-window/cross-attn) runs the seed's per-request
     dense-slot path: one ``T.forward`` per request per iteration (jitted,
     so benchmarks compare batching rather than eager-dispatch overhead).
     Both paths emit real greedy tokens (argmax, fed back as the next
@@ -307,9 +318,9 @@ class ModelExecutor:
     numerics are instead pinned to the ``ref_paged_*`` oracles by
     tests/test_kernels.py, and token parity is what the engine promises.)
 
-    Wall-clock timings on CPU are *measured* (they drive the engine clock
-    in real mode); token values are actually computed, proving the engine
-    + cache + kernels end-to-end.
+    Wall-clock timings are *measured* on whichever backend runs (they
+    drive the engine clock in real mode); token values are actually
+    computed, proving the engine + cache + kernels end-to-end.
     """
 
     def __init__(self, cfg, config: ExecutorConfig | None = None, **kwargs):
@@ -338,6 +349,15 @@ class ModelExecutor:
         self.max_len = config.max_len
         self.max_slots = config.max_slots
         self.paged_ok = T.paged_supported(cfg)
+        if not (self.paged_ok or config.legacy) and \
+                jax.default_backend() == "tpu":
+            # on the device the dense-slot fallback would hide that the
+            # served path is not the batched paged one: ask for it by name
+            raise ValueError(
+                f"{cfg.name} has no paged-KV path (see "
+                "transformer.paged_supported); on TPU the legacy dense-slot "
+                "executor runs only when ExecutorConfig(legacy=True) asks "
+                "for it")
         self.legacy = config.legacy or not self.paged_ok
         # ragged=False pins the block table at the max_pages cap — the
         # fixed-geometry ablation/baseline for the context-sweep benchmark
@@ -351,7 +371,10 @@ class ModelExecutor:
         else:
             self.attn_impl = config.attn_impl
         key = jax.random.PRNGKey(config.seed)
-        self.params = init_params(T.model_decls(cfg), key)
+        # drawn in f32 and cast once, leaf by leaf, to the compute dtype:
+        # the same values forward's per-call cast of f32 masters produced,
+        # held once on the device (that cast is now a no-op)
+        self.params = init_params(T.model_decls(cfg), key, dtype=cfg.dtype)
         # dense per-request slot caches: only the legacy path keeps them
         # (the batched path retires the slot store for attention KV)
         self.caches = ([init_params(T.cache_decls(cfg, 1, self.max_len), key)
@@ -413,28 +436,26 @@ class ModelExecutor:
         return self.allocator.num_pages
 
     def _make_stores(self):
-        from repro.cache.paged import PagedStackStore
+        from repro.cache.paged import PagedStackStore, store_dtype
         cfg = self.cfg
         ppl = self.allocator.num_pages + 1    # +1: per-layer trash page
         page = self.allocator.page_size
-        bytes_total = 0
-        stores = []
-        for period, reps in cfg.stages():
-            stage = {}
-            for bi, _bt in enumerate(period):
-                s = PagedStackStore.build(
-                    reps, ppl, page, cfg.num_kv_heads, cfg.hd)
-                bytes_total += (s.k_pages.size + s.v_pages.size) * \
-                    s.k_pages.dtype.itemsize
-                stage[f"b{bi}"] = s
-            stores.append(stage)
-        if bytes_total > 8 << 30:
+        KV, hd = cfg.num_kv_heads, cfg.hd
+        layers = sum(len(period) * reps for period, reps in cfg.stages())
+        store_bytes = 2 * layers * ppl * page * KV * hd * \
+            self.jnp.dtype(store_dtype()).itemsize
+        param_bytes = sum(a.nbytes for a in self.jax.tree.leaves(self.params))
+        limit = device_bytes_limit()
+        if limit is not None and store_bytes + param_bytes > limit:
             raise ValueError(
-                f"paged stores would need {bytes_total / 2**30:.1f} GiB "
-                f"({ppl} pages/layer x {page}); size EngineConfig.kv_pages "
-                "to the executor (serve.build_stack does this for real "
-                "mode)")
-        return stores
+                f"paged stores ({store_bytes / 2**30:.2f} GiB: {ppl} "
+                f"pages/layer x {page} tokens x {layers} layers) plus params "
+                f"({param_bytes / 2**30:.2f} GiB) exceed the device's "
+                f"{limit / 2**30:.2f} GiB; size EngineConfig.kv_pages to the "
+                "executor (serve.build_stack does this for real mode)")
+        return [{f"b{bi}": PagedStackStore.build(reps, ppl, page, KV, hd)
+                 for bi, _bt in enumerate(period)}
+                for period, reps in cfg.stages()]
 
     @property
     def supports_prefix_cache(self) -> bool:

@@ -84,3 +84,73 @@ def test_config_and_kwargs_together_rejected():
     with pytest.raises(TypeError, match="keyword arguments"):
         ModelExecutor(_cfg(), ExecutorConfig(max_slots=2, max_len=64),
                       max_slots=4)
+
+
+# ---------------- device residency -------------------------------------------
+
+def test_params_resident_in_compute_dtype():
+    """Weights are cast once at construction (forward's per-call cast of
+    f32 masters is then a no-op), holding the same values it produced."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import transformer as T
+    from repro.models.params import init_params
+    cfg = _cfg()
+    ex = ModelExecutor(cfg, ExecutorConfig(max_slots=2, max_len=64))
+    masters = init_params(T.model_decls(cfg), jax.random.PRNGKey(0))
+    for a, m in zip(jax.tree.leaves(ex.params), jax.tree.leaves(masters)):
+        assert a.dtype == cfg.dtype == jnp.bfloat16
+        assert (a == m.astype(cfg.dtype)).all()
+
+
+def test_stores_refused_when_they_exceed_device_memory(monkeypatch):
+    """Paged stores plus params are checked against the device's memory
+    where the backend reports it; over the limit is an error naming both
+    sizes, never a silent cap."""
+    from repro.serving import executors
+    ex = ModelExecutor(_cfg(), ExecutorConfig(max_slots=2, max_len=64))
+    monkeypatch.setattr(executors, "device_bytes_limit", lambda: 1 << 20)
+    with pytest.raises(ValueError, match=r"paged stores .* plus params .* "
+                                         r"exceed the device's 0\.00 GiB"):
+        ex._make_stores()
+    monkeypatch.setattr(executors, "device_bytes_limit", lambda: 1 << 40)
+    assert ex._make_stores()
+
+
+def test_no_silent_legacy_fallback_on_tpu(monkeypatch):
+    """On a TPU backend an arch without a paged path must ask for the
+    legacy executor by name; off the TPU it still falls back."""
+    import jax
+
+    from repro.configs import get_reduced
+    cfg = get_reduced("xlstm-125m")
+    geometry = dict(max_slots=1, max_len=32)
+    assert ModelExecutor(cfg, ExecutorConfig(**geometry)).legacy
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="no paged-KV path"):
+        ModelExecutor(cfg, ExecutorConfig(**geometry))
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """Entry points keep JAX's compile cache where
+    JAX_COMPILATION_CACHE_DIR says, and otherwise in one fixed directory
+    of the checkout."""
+    import jax
+
+    from repro.launch.serve import REPO_ROOT, use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(REPO_ROOT / ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        jax.config.update("jax_compilation_cache_dir", want)
+    try:
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    assert (REPO_ROOT / "chip_smoke.py").is_file()

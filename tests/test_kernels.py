@@ -159,6 +159,18 @@ def test_paged_prefill_kernel_gqa_softcap(H, KV, softcap):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
+def test_paged_prefill_kernel_query_tiles():
+    """Chunks longer than one query tile (G=8 -> 128 queries per tile):
+    a row spanning two tiles, one whose second tile is part padding, and
+    one whose second tile is all padding (it reads no pages)."""
+    from repro.kernels.ref import ref_paged_prefill_attention
+    args = _paged_prefill_case([3, 0, 5], [200, 130, 20], page=8, H=8, KV=1,
+                               seed=3)
+    out = ops.paged_prefill_attention(*args)
+    ref = ref_paged_prefill_attention(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
 def test_paged_prefill_length_zero_rows_are_exact_zero():
     args = _paged_prefill_case([0, 7], [0, 3])
     out = np.asarray(ops.paged_prefill_attention(*args))
