@@ -46,6 +46,7 @@ from dataclasses import dataclass, field
 from repro.cache import BlockAllocator, OutOfPages
 from repro.core.queues import QueueManager
 from repro.core.scheduler import SchedulerPolicy
+from repro.serving import tracing
 from repro.serving.encoder_cache import EncoderCache
 from repro.serving.journal import Journal
 from repro.serving.request import (TERMINAL_STATES, Request, State,
@@ -220,6 +221,10 @@ class Engine:
         """Move arrived requests into the classified waiting queues.
         ``pending`` is sorted by arrival; returns the new start index (the
         seed rebuilt the whole list every iteration — O(N) per step)."""
+        with tracing.span("engine.ingest"):
+            return self._ingest_due(pending, start)
+
+    def _ingest_due(self, pending: list[Request], start: int) -> int:
         i, n = start, len(pending)
         while i < n and pending[i].arrival <= self.now:
             req = pending[i]
@@ -324,6 +329,7 @@ class Engine:
                                 "(encoding)")
             else:
                 self.queues.push(req, self.now)
+                tracing.event("queued", req.rid)
                 if self.journal is not None:
                     self._jrec("state", req.rid, State.WAITING.value)
                 if self.faults is not None and \
@@ -649,6 +655,7 @@ class Engine:
             req.preempted_at = None
         if req.admit_time is None:
             req.admit_time = self.now
+            tracing.event("admitted", req.rid)
         req.state = State.PREFILLING
         self.prefilling[req] = None
         if self.journal is not None:
@@ -662,20 +669,23 @@ class Engine:
     def _plan(self):
         """Pick this iteration's encode chunks, decode batch + prefill
         chunks."""
-        encode_work = self._plan_encode()
-        budget = self.config.token_budget
-        decode_batch = list(self.running)
-        budget -= len(decode_batch)
-        if self.config.decode_priority and any(
-                r.vclass is VehicleClass.MOTORCYCLE for r in decode_batch):
-            # protect latency-critical inter-token latency: cap the prefill
-            # share while motorcycles are decoding (beyond-paper)
-            budget = min(budget, int(self.config.token_budget *
-                                     self.config.decode_priority_frac))
-        if self.config.legacy_scheduling:
-            prefill_work = self._plan_prefill_legacy(budget)
-        else:
-            prefill_work = self._plan_prefill(budget)
+        with tracing.span("engine.plan"):
+            encode_work = self._plan_encode()
+            budget = self.config.token_budget
+            decode_batch = list(self.running)
+            budget -= len(decode_batch)
+            if self.config.decode_priority and any(
+                    r.vclass is VehicleClass.MOTORCYCLE
+                    for r in decode_batch):
+                # protect latency-critical inter-token latency: cap the
+                # prefill share while motorcycles are decoding
+                # (beyond-paper)
+                budget = min(budget, int(self.config.token_budget *
+                                         self.config.decode_priority_frac))
+            if self.config.legacy_scheduling:
+                prefill_work = self._plan_prefill_legacy(budget)
+            else:
+                prefill_work = self._plan_prefill(budget)
         return prefill_work, decode_batch, encode_work
 
     def _plan_encode(self) -> list[tuple[Request, int]]:
@@ -868,6 +878,13 @@ class Engine:
         return False
 
     def _step_core(self, pending: list[Request], start: int) -> int:
+        with tracing.span("engine.step") as sp:
+            start = self._iterate(pending, start)
+            if sp:
+                tracing.gauge("kv.pages_used", self.allocator.used_pages)
+        return start
+
+    def _iterate(self, pending: list[Request], start: int) -> int:
         start = self._ingest(pending, start)
         if self._deadline_heap:
             self._expire_deadlines()
@@ -920,11 +937,20 @@ class Engine:
                 self.now += self.config.retry_backoff_s * (2 ** attempt)
                 attempt += 1
         plan_now = self.now
-        duration = self.executor.run_iteration(prefill_work, decode_batch,
-                                               encode_work)
+        with tracing.span("step"):
+            duration = self.executor.run_iteration(prefill_work,
+                                                   decode_batch, encode_work)
         self.now += duration
         self.iterations += 1
+        with tracing.span("engine.commit"):
+            self._commit(prefill_work, decode_batch, encode_work, plan_now)
+        return start
 
+    def _commit(self, prefill_work, decode_batch, encode_work,
+                plan_now: float) -> None:
+        """Book one executed iteration: encode completion, prefill
+        progress and prefix publication, KV growth (preempting under
+        pressure), finishing and freeing."""
         cache = self.encoder_cache
         for req, units in encode_work:
             if self.faults is not None and self.faults.encoder_fault(req):
@@ -954,6 +980,7 @@ class Engine:
                     cache.insert(req.mm_hash, req.mm_units)
                 req.state = State.WAITING
                 self.queues.push(req, self.now)
+                tracing.event("queued", req.rid)
                 if self.journal is not None:
                     self._jrec("state", req.rid, State.WAITING.value)
                 if self.faults is not None and \
@@ -1051,7 +1078,6 @@ class Engine:
             if self.journal is not None:
                 self._jrec("release", req.rid)
                 self._jrec("terminal", req.rid, State.FINISHED.value)
-        return start
 
     def step(self, pending: list[Request]) -> list[Request]:
         # the cursor-based core needs arrival order; the seed's step

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core.profiler import ProfileRecord
 
+from . import tracing
 from .request import TERMINAL_STATES, Modality, Request
 
 
@@ -658,6 +659,23 @@ class ModelExecutor:
     # -- profiler interface -------------------------------------------------
     def isolated_run(self, req: Request) -> ProfileRecord:
         n = min(req.prompt_tokens, self.max_len - 8)
+        with tracing.span("admission.profile"):
+            meas, prefill = self._measure_prefill(req, n)
+        if meas < n:
+            prefill = (prefill * n / meas if meas > 0
+                       else getattr(self, "_profile_rate", 1e-4) * n)
+        if n > 0 and meas > 0:
+            self._profile_rate = prefill / n
+        self.release_slot(req)
+        self._prompt_cache.pop(req.rid, None)
+        return ProfileRecord(
+            modality=req.modality.value, text_tokens=req.text_tokens,
+            mm_units=req.mm_units, prompt_tokens=req.prompt_tokens,
+            preprocess_time=0.0, encode_time=0.0, prefill_time=prefill)
+
+    def _measure_prefill(self, req: Request, n: int) -> tuple[int, float]:
+        """(tokens measured, seconds) of one isolated prefill of the
+        first ``n`` prompt tokens."""
         meas = n
         t0 = time.perf_counter()
         if self.legacy:
@@ -683,22 +701,12 @@ class ModelExecutor:
                 try:
                     toks = self._prompt_tokens(req)[:meas]
                     out = self._paged_prefill_call(
-                        [(rid, toks, 0, 0, meas)])
-                    out.block_until_ready()
+                        [(rid, toks, 0, 0, meas)], counted=False)
+                    with tracing.span("step.prefill.sync"):
+                        out.block_until_ready()
                 finally:
                     self.allocator.free(rid)
-        prefill = time.perf_counter() - t0
-        if meas < n:
-            prefill = (prefill * n / meas if meas > 0
-                       else getattr(self, "_profile_rate", 1e-4) * n)
-        if n > 0 and meas > 0:
-            self._profile_rate = prefill / n
-        self.release_slot(req)
-        self._prompt_cache.pop(req.rid, None)
-        return ProfileRecord(
-            modality=req.modality.value, text_tokens=req.text_tokens,
-            mm_units=req.mm_units, prompt_tokens=req.prompt_tokens,
-            preprocess_time=0.0, encode_time=0.0, prefill_time=prefill)
+        return meas, time.perf_counter() - t0
 
     def isolated_e2e(self, req: Request) -> float:
         ttft = self._isolated_ttft.get(req.rid)
@@ -760,8 +768,10 @@ class ModelExecutor:
     # -- engine interface ----------------------------------------------------
     def run_iteration(self, prefill_work, decode_reqs, encode_work) -> float:
         t0 = time.perf_counter()
-        for req, units in encode_work:
-            self.encode_chunk(req, units)
+        if encode_work:
+            with tracing.span("step.encode"):
+                for req, units in encode_work:
+                    self.encode_chunk(req, units)
         step = self._legacy_iteration if self.legacy else \
             self._batched_iteration
         step(self._prefill_rows(prefill_work),
@@ -820,37 +830,54 @@ class ModelExecutor:
             bt[i, :len(pages)] = pages
         return bt
 
-    def _paged_prefill_call(self, rows):
+    def _paged_prefill_call(self, rows, counted: bool = True):
         """rows: [(rid, tokens ndarray, rope_start, write_start, n)].
-        Runs one packed jit'd prefill step; returns last-token ids (B,)."""
-        jnp = self.jnp
+        Runs one packed jit'd prefill step; returns last-token ids (B,).
+        ``counted`` adds its tokens to the ``tokens.*.prefill`` counters
+        (admission profiling passes False)."""
+        with tracing.span("step.prefill.prep") as sp:
+            B = self._bucket(len(rows))
+            C = self._bucket(max(n for *_x, n in rows))
+            page = self.allocator.page_size
+            # live pages after this call's writes: ceil((write_start+n)/page)
+            maxp = self._bucket_pages(
+                max(-(-(ws + n) // page) for _r, _t, _rs, ws, n in rows))
+            toks = np.zeros((B, C), np.int32)
+            pos = np.zeros((B, C), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            new_lens = np.zeros((B,), np.int32)
+            for i, (_rid, t, rope_start, write_start, n) in enumerate(rows):
+                toks[i, :n] = t
+                pos[i] = rope_start + np.arange(C)
+                lengths[i] = write_start
+                new_lens[i] = n
+            bt = np.full((B, maxp), self.allocator.num_pages, np.int32)
+            bt[:len(rows)] = self._block_table_rows([r[0] for r in rows],
+                                                    maxp)
+            if sp and counted:
+                self._count_tokens("prefill", B * C, int(new_lens.sum()))
+        return self._dispatch("step.prefill.dispatch", ("prefill", B, C, maxp),
+                              (toks, pos, bt, lengths, new_lens))
+
+    @staticmethod
+    def _count_tokens(label: str, slots: int, live: int) -> None:
+        tracing.count("tokens.live." + label, live)
+        tracing.count("tokens.padded." + label, slots - live)
+
+    def _dispatch(self, name: str, key: tuple, args: tuple):
+        """One call of the jitted step at signature ``key`` (which joins
+        ``recompile_keys``), spanned as ``name``; returns the last-token
+        ids (B,) before they reach the host."""
         if self._stores is None:
             self._stores = self._make_stores()
-        B = self._bucket(len(rows))
-        C = self._bucket(max(n for *_x, n in rows))
-        page = self.allocator.page_size
-        # live pages after this call's writes: ceil((write_start+n)/page)
-        maxp = self._bucket_pages(
-            max(-(-(ws + n) // page) for _r, _t, _rs, ws, n in rows))
-        self.recompile_keys.add(("prefill", B, C, maxp))
-        toks = np.zeros((B, C), np.int32)
-        pos = np.zeros((B, C), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        new_lens = np.zeros((B,), np.int32)
-        for i, (_rid, t, rope_start, write_start, n) in enumerate(rows):
-            toks[i, :n] = t
-            pos[i] = rope_start + np.arange(C)
-            lengths[i] = write_start
-            new_lens[i] = n
-        bt = np.full((B, maxp), self.allocator.num_pages, np.int32)
-        bt[:len(rows)] = self._block_table_rows([r[0] for r in rows], maxp)
-        out, self._stores = self._prefill_jit(
-            self.params, self._stores, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(bt), jnp.asarray(lengths), jnp.asarray(new_lens))
+        self.recompile_keys.add(key)
+        with tracing.span(name):
+            out, self._stores = self._prefill_jit(
+                self.params, self._stores,
+                *(self.jnp.asarray(a) for a in args))
         return out
 
     def _batched_iteration(self, prefill_rows, decode_rows):
-        jnp = self.jnp
         # waves: a request may legitimately appear twice in one plan
         # (preempted then re-admitted); its chunks must apply in order and
         # never share one scatter (duplicate indices are unordered)
@@ -869,39 +896,40 @@ class ModelExecutor:
                      rope, self._ctx.get(req.rid, 0), n)
                     for req, rope, n, _d in wave]
             out = self._paged_prefill_call(rows)
-            out = np.asarray(out)
+            with tracing.span("step.prefill.sync"):
+                out = np.asarray(out)
             for i, (req, _rope, n, done) in enumerate(wave):
                 self._ctx[req.rid] = rows[i][3] + n
                 if done:
                     self.emitted.setdefault(req.rid, []).append(int(out[i]))
         if not decode_rows:
             return
-        if self._stores is None:
-            self._stores = self._make_stores()
-        B = self._bucket(len(decode_rows))
-        page = self.allocator.page_size
-        # each row writes one token at position ctx, so the live context
-        # after the step is ctx+1 tokens
-        maxp = self._bucket_pages(
-            max(-(-(self._ctx[r.rid] + 1) // page) for r in decode_rows))
-        self.recompile_keys.add(("decode", B, maxp))
-        toks = np.zeros((B, 1), np.int32)
-        pos = np.zeros((B, 1), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        new_lens = np.zeros((B,), np.int32)
-        for i, req in enumerate(decode_rows):
-            toks[i, 0] = self.emitted[req.rid][-1]
-            pos[i, 0] = min(req.prompt_tokens + req.decoded - 1,
-                            self.max_len - 2)
-            lengths[i] = self._ctx[req.rid]
-            new_lens[i] = 1
-        bt = np.full((B, maxp), self.allocator.num_pages, np.int32)
-        bt[:len(decode_rows)] = self._block_table_rows(
-            [r.rid for r in decode_rows], maxp)
-        out, self._stores = self._prefill_jit(
-            self.params, self._stores, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(bt), jnp.asarray(lengths), jnp.asarray(new_lens))
-        out = np.asarray(out)
+        with tracing.span("step.decode.prep") as sp:
+            B = self._bucket(len(decode_rows))
+            page = self.allocator.page_size
+            # each row writes one token at position ctx, so the live
+            # context after the step is ctx+1 tokens
+            maxp = self._bucket_pages(
+                max(-(-(self._ctx[r.rid] + 1) // page) for r in decode_rows))
+            toks = np.zeros((B, 1), np.int32)
+            pos = np.zeros((B, 1), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            new_lens = np.zeros((B,), np.int32)
+            for i, req in enumerate(decode_rows):
+                toks[i, 0] = self.emitted[req.rid][-1]
+                pos[i, 0] = min(req.prompt_tokens + req.decoded - 1,
+                                self.max_len - 2)
+                lengths[i] = self._ctx[req.rid]
+                new_lens[i] = 1
+            bt = np.full((B, maxp), self.allocator.num_pages, np.int32)
+            bt[:len(decode_rows)] = self._block_table_rows(
+                [r.rid for r in decode_rows], maxp)
+            if sp:
+                self._count_tokens("decode", B, len(decode_rows))
+        out = self._dispatch("step.decode.dispatch", ("decode", B, maxp),
+                             (toks, pos, bt, lengths, new_lens))
+        with tracing.span("step.decode.sync"):
+            out = np.asarray(out)
         for i, req in enumerate(decode_rows):
             self._ctx[req.rid] += 1
             self.emitted[req.rid].append(int(out[i]))
