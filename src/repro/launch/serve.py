@@ -88,6 +88,10 @@ def build_stack(arch: str, executor_kind: str = "sim", *,
         # default A100-sized kv_pages would build gigabyte page arrays)
         kv_pages = exec_cfg.num_pages
     profile = WorkloadProfiler(executor, arch).build(prof_reqs)
+    if executor_kind != "sim":
+        # the SLO price table, measured during set-up: the engine then
+        # prices every request by lookup, with no prefill on the serve path
+        executor.prefill_prices()
     est = ImpactEstimator.train(profile)
     classifier = (NaiveClassifier(est) if naive_classifier
                   else SmartClassifier.train(est, profile))

@@ -392,10 +392,9 @@ class ModelExecutor:
                                         page_size=config.page_size)
         self._stores = None           # lazy: [{bname: PagedStackStore}]
         self._ctx: dict[str, int] = {}        # KV tokens written per rid
-        self._isolated_ttft: dict[str, float] = {}  # measured profile
-        #   prefill per rid: repricing an SLO at admission (prefix claim
-        #   shifted) must not re-run a profile prefill — the pool may be
-        #   full at that point
+        # isolated-prefill seconds per one-row signature (SLO pricing);
+        # measured once, by build_stack at set-up or on first use
+        self._prefill_prices: dict[tuple, float] | None = None
         self.emitted: dict[str, list[int]] = {}
         self._finished_rids = deque()
         self._prompt_cache: dict[str, np.ndarray] = {}
@@ -426,6 +425,8 @@ class ModelExecutor:
         """Adopt the engine's BlockAllocator: its page ids index the paged
         stores directly (id P — one past the allocator's last — is the
         reserved trash page for ragged-batch padding writes)."""
+        if allocator.page_size != self.allocator.page_size:
+            self._prefill_prices = None   # signatures follow the page size
         if self._stores is not None and (
                 allocator.num_pages != self.allocator.num_pages
                 or allocator.page_size != self.allocator.page_size):
@@ -490,11 +491,10 @@ class ModelExecutor:
 
     # -- page-chain migration payloads (ISSUE 9) -----------------------------
     def evict_request(self, rid: str) -> None:
-        """Drop every per-rid memo (prompt stream, profile, emitted) for a
+        """Drop every per-rid memo (prompt stream, emitted, KV cursor) for a
         request exported off this replica — it will never run here again,
         so the non-terminal retention in ``release_slot`` does not apply."""
         self._prompt_cache.pop(rid, None)
-        self._isolated_ttft.pop(rid, None)
         self.emitted.pop(rid, None)
         self._ctx.pop(rid, None)
 
@@ -638,10 +638,9 @@ class ModelExecutor:
         self._ctx.pop(req.rid, None)
         if req.state in TERMINAL_STATES:
             # terminal (finished/rejected/failed/cancelled) requests never
-            # run again: their profile memo and token arrays must not
-            # outlive them (rejected ones carry the *largest* prompts)
+            # run again: their token arrays must not outlive them
+            # (rejected ones carry the *largest* prompts)
             self._prompt_cache.pop(req.rid, None)
-            self._isolated_ttft.pop(req.rid, None)
             if req.rid in self.emitted:
                 self._finished_rids.append(req.rid)
                 while len(self._finished_rids) > self.EMITTED_RETAIN:
@@ -708,11 +707,49 @@ class ModelExecutor:
                     self.allocator.free(rid)
         return meas, time.perf_counter() - t0
 
+    def _profile_signature(self, n: int) -> tuple:
+        """(B, C, table width) of the one-row call that isolated-prefills
+        ``n`` prompt tokens (``_paged_prefill_call``'s bucketing)."""
+        page = self.allocator.page_size
+        return (1, self._bucket(n), self._bucket_pages(-(-n // page)))
+
+    def prefill_prices(self) -> dict[tuple, float]:
+        """Seconds of one isolated prefill at every one-row signature a
+        prompt cut to the context window reaches, each measured at its
+        longest prompt; the table ``isolated_e2e`` prices SLOs from.
+        Built on the first call (``build_stack`` makes it at set-up),
+        the same for the legacy path, which measures through its own
+        jitted call. Each signature runs twice and keeps the second
+        time, so no price holds a compile or a compile-cache load. A
+        price is at most that of every longer prompt's signature, so a
+        host stall inside one short measurement cannot price it above
+        longer prompts, and the price never falls as a prompt grows."""
+        if self._prefill_prices is not None:
+            return self._prefill_prices
+        longest = {self._profile_signature(n): n
+                   for n in range(1, self.max_len - 7)}
+        prices, ceiling = {}, float("inf")
+        for sig, n in sorted(longest.items(), key=lambda kv: -kv[1]):
+            req = Request(rid=f"__price__{n}", modality=Modality.TEXT,
+                          arrival=0.0, text_tokens=n, prompt_tokens=n)
+            for _ in range(2):
+                with tracing.span("admission.profile"):
+                    meas, secs = self._measure_prefill(req, n)
+                self.release_slot(req)
+            self._prompt_cache.pop(req.rid, None)
+            secs = (secs * n / meas if meas > 0
+                    else getattr(self, "_profile_rate", 1e-4) * n)
+            ceiling = prices[sig] = min(ceiling, secs)
+        self._prefill_prices = prices
+        return prices
+
     def isolated_e2e(self, req: Request) -> float:
-        ttft = self._isolated_ttft.get(req.rid)
-        if ttft is None:
-            ttft = self.isolated_run(req).ttft
-            self._isolated_ttft[req.rid] = ttft
+        """SLO pricing: the measured isolated prefill of the request's
+        signature (``prefill_prices``), a lookup that dispatches
+        nothing."""
+        n = min(req.prompt_tokens, self.max_len - 8)
+        ttft = (self.prefill_prices()[self._profile_signature(n)]
+                if n > 0 else 0.0)
         cached = min(req.cached_prefix_tokens, max(req.prompt_tokens - 1, 0))
         if cached > 0 and req.prompt_tokens > 0:
             # measured prefill is ~linear in tokens at these sizes: price
